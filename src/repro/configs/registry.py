@@ -54,12 +54,14 @@ H2O_DANUBE = _reg(ModelConfig(
     subquadratic=True,   # SWA bounds the KV cache -> long_500k runnable
 ))
 
-# qk_norm, GQA [hf:Qwen/Qwen3-8B; hf]
+# qk_norm, GQA [hf:Qwen/Qwen3-0.6B config.json: hidden_size 1024,
+# intermediate_size 3072, 28 layers, 16/8 heads of head_dim 128,
+# vocab_size 151936, rope_theta 1e6, rms_norm_eps 1e-6, tied embeddings]
 QWEN3 = _reg(ModelConfig(
     name="qwen3-0.6b", family="dense",
     n_layers=28, d_model=1024, n_heads=16, n_kv_heads=8,
     d_ff=3072, vocab=151936, head_dim=128, qk_norm=True,
-    rope_theta=1e6, tie_embeddings=True,
+    rope_theta=1e6, tie_embeddings=True, norm_eps=1e-6,
 ))
 
 # small llama3 [hf:meta-llama/Llama-3.2-1B; unverified]

@@ -33,29 +33,34 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, st_ref, *,
         st_ref[...] = jnp.zeros_like(st_ref)
 
     x = x_ref[0].astype(jnp.float32)          # (chunk, P)
-    a = a_ref[0].astype(jnp.float32)          # (chunk,)
+    a = a_ref[0].astype(jnp.float32)          # (1, chunk)
     B = b_ref[0].astype(jnp.float32)          # (chunk, N)
     C = c_ref[0].astype(jnp.float32)          # (chunk, N)
 
-    cs = jnp.cumsum(a)                        # (chunk,)
-    total = cs[-1]
+    # cumulative log-decay as a column and a row, by masked reductions
+    # (Mosaic lowers no cumsum and no 1-D <-> 2-D relayout)
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = row >= col
+    cs_col = jnp.sum(jnp.where(tri, a, 0.0), axis=1, keepdims=True)
+    a_col = jnp.sum(jnp.where(row == col, a, 0.0), axis=1, keepdims=True)
+    cs_row = jnp.sum(jnp.where(row <= col, a_col, 0.0), axis=0,
+                     keepdims=True)           # (1, chunk)
+    total = jnp.sum(a, axis=1, keepdims=True)  # (1, 1)
     # intra-chunk: G[i,j] = C_i·B_j * exp(cs_i - cs_j) for j <= i
     scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    seg = cs[:, None] - cs[None, :]
-    tri = (jax.lax.iota(jnp.int32, chunk)[:, None]
-           >= jax.lax.iota(jnp.int32, chunk)[None, :])
-    G = jnp.where(tri, scores * jnp.exp(seg), 0.0)
+    G = jnp.where(tri, scores * jnp.exp(cs_col - cs_row), 0.0)
     y = jax.lax.dot_general(G, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     # inter-chunk: contribution of the carried state
     st = st_ref[...]                          # (P, N)
-    y += jnp.exp(cs)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cs_col) * jax.lax.dot_general(
         C, st, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     y_ref[0] = y.astype(y_ref.dtype)
     # state update
-    w = jnp.exp(total - cs)[:, None] * B       # (chunk, N)
+    w = jnp.exp(total - cs_col) * B           # (chunk, N)
     st_ref[...] = (jnp.exp(total) * st
                    + jax.lax.dot_general(x, w, (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32))
@@ -82,7 +87,9 @@ def ssd_scan_kernel(x: jax.Array, a: jax.Array, B: jax.Array,
     n_chunks = lp // chunk
 
     xr = x.transpose(0, 2, 1, 3).reshape(b * h, lp, p)
-    ar = a.transpose(0, 2, 1).reshape(b * h, lp)
+    # (b*h, 1, L): a (1, chunk) block is legal on the TPU (the last two
+    # block dims must be divisible by (8, 128) or span the array)
+    ar = a.transpose(0, 2, 1).reshape(b * h, 1, lp)
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk, n_chunks=n_chunks)
     y = pl.pallas_call(
@@ -90,7 +97,7 @@ def ssd_scan_kernel(x: jax.Array, a: jax.Array, B: jax.Array,
         grid=(b * h, n_chunks),
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda bh, c: (bh, c, 0)),
-            pl.BlockSpec((1, chunk), lambda bh, c: (bh, c)),
+            pl.BlockSpec((1, 1, chunk), lambda bh, c: (bh, 0, c)),
             pl.BlockSpec((1, chunk, n), lambda bh, c, h=h: (bh // h, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda bh, c, h=h: (bh // h, c, 0)),
         ],

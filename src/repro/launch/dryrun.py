@@ -151,8 +151,6 @@ def lower_cell(arch: str, shape_name: str, mesh, **rules_kw) -> dict:
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
 

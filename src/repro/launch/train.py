@@ -41,10 +41,10 @@ def main() -> None:
     model = build_model(cfg)
     shard = None
     if args.mesh:
-        from repro.launch.mesh import make_host_mesh
+        from repro.launch.mesh import make_mesh
         from repro.parallel.sharding import MeshRules
         d, m = (int(x) for x in args.mesh.split(","))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
         shard = MeshRules(mesh)
     params = model.init(jax.random.key(0))
     print(f"[train] {cfg.name}: ~{cfg.param_count():.2e} params, "

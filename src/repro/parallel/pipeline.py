@@ -18,15 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax >= 0.6 promotes shard_map to the top level (replication checking is
-# spelled check_vma there); older releases keep it in experimental with
-# check_rep.  Support both so the dry-run works across toolchains.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KW = {"check_vma": False}
-else:  # pragma: no cover - exercised on jax < 0.6 toolchains
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = {"check_rep": False}
 
 
 def pipeline_forward(stage_fn, stage_params, x_micro, *, mesh,
@@ -74,11 +65,11 @@ def pipeline_forward(stage_fn, stage_params, x_micro, *, mesh,
         # (all other stages contribute zeros)
         return jax.lax.psum(outs, axis)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()),      # stage dim sharded; input replicated
         out_specs=P(),
-        **_CHECK_KW,
+        check_vma=False,
     )
     return fn(stage_params, x_micro)
 

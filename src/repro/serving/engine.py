@@ -54,6 +54,22 @@ class EngineConfig:
     trace: bool = False
 
 
+def slot_cache(model: Model, slots: int, max_seq: int) -> dict:
+    """Stacked per-slot caches: every leaf of ``init_cache(1, max_seq)``
+    with a leading slot axis."""
+    return jax.tree.map(
+        lambda a: jnp.broadcast_to(a, (slots,) + a.shape).copy(),
+        model.init_cache(1, max_seq))
+
+
+def decode_fn(model: Model):
+    """The engine's jitted decode step, vmapped over slots:
+    (params, tokens (slots, 1, 1), slot cache) -> (logits, slot cache).
+    The params are an argument, not a closed-over constant, so the
+    weights stay out of the compiled program and its cache key."""
+    return jax.jit(jax.vmap(model.decode_step, in_axes=(None, 0, 0)))
+
+
 class InferenceEngine:
     """Single-host serving engine (smoke scale on CPU, shardable on TPU)."""
 
@@ -100,15 +116,21 @@ class InferenceEngine:
                 self.plane.tracer = self.tracer
                 self.plane.trace_source = "engine"
                 self.plane.recorder = self.recorder
+        # the engine lives on the device that holds its params: caches and
+        # step inputs are placed beside them, so replicas whose params sit
+        # on different chips each run on their own chip
+        self.device = jax.tree.leaves(params)[0].device
         # stacked per-slot caches: leaf shape (slots, ...)
-        single = model.init_cache(1, self.cfg.max_seq)
-        self.slot_cache = jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (self.cfg.max_slots,) + a.shape)
-            .copy(), single)
-        self._decode_vmapped = jax.jit(jax.vmap(
-            lambda tok, cache: model.decode_step(self.params, tok, cache),
-            in_axes=(0, 0)))
+        with jax.default_device(self.device):
+            self.slot_cache = slot_cache(model, self.cfg.max_slots,
+                                         self.cfg.max_seq)
+        self._decode_vmapped = decode_fn(model)
         self._prefill_jit: dict[int, callable] = {}
+        # observer of every logits row the engine computes:
+        # on_logits({row: request}, consumed tokens (rows, n), logits
+        # (rows, vocab)) — how a caller checks served logits against a
+        # reference without a second code path
+        self.on_logits = None
         self.clock = 0.0
         self.completed: list[ServeRequest] = []
         self.kv_compress = False
@@ -182,10 +204,11 @@ class InferenceEngine:
 
     def _prefill_fn(self, bucket: int):
         if bucket not in self._prefill_jit:
-            model = self.model
+            model, max_seq = self.model, self.cfg.max_seq
 
-            def prefill_one(params, tokens, cache):
-                return model.prefill(params, tokens, cache)
+            def prefill_one(params, tokens):
+                return model.prefill(params, tokens,
+                                     model.init_cache(1, max_seq))
 
             self._prefill_jit[bucket] = jax.jit(prefill_one)
         return self._prefill_jit[bucket]
@@ -212,12 +235,13 @@ class InferenceEngine:
         bucket = self.sched.bucket_len(req.prompt_len)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, -req.prompt_len:] = req.prompt    # left-pad into bucket
-        toks = jnp.asarray(toks)
         self._emit(EventKind.H2D_XFER, device=slot % 4,
                    size=int(toks.size * 4), flow=req.req_id)
-        fresh = self.model.init_cache(1, self.cfg.max_seq)
         self._emit(EventKind.DISPATCH, device=slot % 4)
-        logits, cache = self._prefill_fn(bucket)(self.params, toks, fresh)
+        logits, cache = self._prefill_fn(bucket)(
+            self.params, jax.device_put(toks, self.device))
+        if self.on_logits is not None:
+            self.on_logits({0: req}, toks, logits[:, -1])
         # first-token logits return to the host (pairs with the dispatch)
         self._emit(EventKind.D2H_XFER, device=slot % 4,
                    size=int(logits.size * 4), flow=req.req_id)
@@ -266,9 +290,12 @@ class InferenceEngine:
         for s in slots:
             toks[s, 0, 0] = self._slot_next_token.get(s, 0)
         self._emit(EventKind.DISPATCH, device=0)
-        logits, new_cache = self._decode_vmapped(jnp.asarray(toks),
-                                                 self.slot_cache)
+        logits, new_cache = self._decode_vmapped(
+            self.params, jax.device_put(toks, self.device), self.slot_cache)
         self.slot_cache = new_cache
+        if self.on_logits is not None:
+            self.on_logits({s: self.sched.running[s] for s in slots},
+                           toks[:, 0], logits[:, 0, -1])
         self._emit(EventKind.D2H_XFER, device=0,
                    size=len(slots) * 4)
         self.stats["steps"] += 1

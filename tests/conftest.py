@@ -1,3 +1,9 @@
+import os
+
+# the suite runs on the CPU: on a TPU host JAX would otherwise take the
+# chips, and tests that count devices or force a CPU device count break
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 import pytest
 
 

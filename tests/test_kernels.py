@@ -136,3 +136,12 @@ def test_ops_dispatch_cpu_uses_ref():
     want = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=1e-6)
+
+
+def test_force_kernel_off_tpu_is_an_error():
+    """force_kernel never falls back to the interpreter in silence."""
+    q = jnp.zeros((1, 64, 4, 64), jnp.float32)
+    with pytest.raises(RuntimeError, match="needs the TPU backend"):
+        ops.flash_attention(q, q, q, force_kernel=True)
+    out = ops.flash_attention(q, q, q, force_kernel=True, interpret=True)
+    assert out.shape == q.shape

@@ -25,9 +25,10 @@ class TestFit:
             import os
             os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
             import jax
-            from jax.sharding import PartitionSpec as P
+            from jax.sharding import AxisType, PartitionSpec as P
             from repro.parallel.sharding import fit
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = jax.make_mesh((2, 4), ("data", "model"),
+                                 axis_types=(AxisType.Auto,) * 2)
             # batch=1 cannot shard over data
             assert fit(mesh, (1, 64), (("data",), "model")) == P(None, "model")
             # dim divisible by both axes keeps both
@@ -52,11 +53,13 @@ def test_dryrun_small_mesh_all_families():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses, jax, jax.numpy as jnp
+        from jax.sharding import AxisType
         from repro.configs import ARCHS
         from repro.models import build_model
         from repro.parallel.sharding import MeshRules
         from repro.models.model import ShapeSpec
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         rules = MeshRules(mesh, fsdp=True)
         for arch in ["llama3.2-3b", "qwen2-moe-a2.7b", "zamba2-7b",
                      "xlstm-125m", "seamless-m4t-large-v2",
@@ -74,10 +77,7 @@ def test_dryrun_small_mesh_all_families():
             with mesh:
                 c = jax.jit(loss, in_shardings=(psh, bsh)).lower(
                     ps, specs["batch"]).compile()
-            cost = c.cost_analysis()
-            if isinstance(cost, (list, tuple)):   # older jax: per-device list
-                cost = cost[0] if cost else {}
-            assert cost["flops"] > 0
+            assert c.cost_analysis()["flops"] > 0
             # decode too
             dshape = ShapeSpec("d", "decode", 64, 8)
             dspecs = m.input_specs(dshape)
@@ -107,8 +107,10 @@ def test_pipeline_parallel_over_pod_axis():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.parallel.pipeline import pipeline_forward, bubble_fraction
-        mesh = jax.make_mesh((2, 4), ("pod", "model"))
+        mesh = jax.make_mesh((2, 4), ("pod", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         n_stages, n_micro, mb, d = 2, 4, 2, 16
         key = jax.random.key(0)
         w = jax.random.normal(key, (n_stages, d, d)) * 0.1
@@ -139,16 +141,19 @@ def test_elastic_remesh_preserves_values():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.parallel.sharding import MeshRules
         from repro.training.elastic import plan_remesh, remesh
-        old = jax.make_mesh((4, 2), ("data", "model"))
+        old = jax.make_mesh((4, 2), ("data", "model"),
+                            axis_types=(AxisType.Auto,) * 2)
         rules = MeshRules(old)
         params = {"wq": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
         sh = rules.shardings_of(rules.param_specs(params))
         params = jax.tree.map(jax.device_put, params, sh)
         plan = plan_remesh(old, failed_nodes=2)
         assert plan.new_shape["data"] == 2 and plan.micro_scale == 2
-        new_mesh = jax.make_mesh((2, 2), ("data", "model"))
+        new_mesh = jax.make_mesh((2, 2), ("data", "model"),
+                                 axis_types=(AxisType.Auto,) * 2)
         new_params, _ = remesh(params, rules, new_mesh)
         np.testing.assert_array_equal(np.asarray(new_params["wq"]),
                                       np.arange(64).reshape(8, 8))
