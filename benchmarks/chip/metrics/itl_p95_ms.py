@@ -1,0 +1,9 @@
+"""95th percentile over every gap between consecutive tokens of every
+request in the window."""
+
+from benchmarks.chip.stats import quantile, token_gaps
+
+
+def read(run):
+    q = quantile(token_gaps(run.record), 0.95)
+    return None if q is None else q * 1e3
