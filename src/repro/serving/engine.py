@@ -8,17 +8,22 @@ exact event schema the detectors consume: INGRESS on request arrival, H2D
 around prefill feeds, DISPATCH per step, D2H per step, EGRESS per token,
 QUEUE_SAMPLE per scheduler tick — and the engine implements EngineControls
 so the mitigation controller can close the loop (§5).
+
+Each phase of the loop is a host span (``SPANS``) in the profiler's own
+trace, so a traced run puts the device's idle time down to engine phases on
+the device trace's clock.  A span costs about a microsecond when no
+profiler runs, so they are always on; the engine itself reads no clock.
 """
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from repro.core.detectors import (
     META_DIR_INGRESS,
@@ -31,6 +36,26 @@ from repro.core.telemetry import TelemetryPlane
 from repro.models import Model
 from repro.serving.kvcache import PagedKVPool
 from repro.serving.scheduler import Scheduler, SchedulerConfig, ServeRequest
+
+# The engine's host spans.  ``engine.<phase>.<part>`` lies inside
+# ``engine.<phase>``, and ``engine.prefill`` inside ``engine.admit``.
+# ``engine.prefill`` carries req_id, slot and bucket, ``engine.decode`` step
+# and slots (the running count), ``engine.flush`` events (the batch's
+# length).
+SPANS = (
+    "engine.admit",                 # _admit_loop, enclosing the prefills
+    "engine.prefill",               # _prefill
+    "engine.prefill.dispatch",      # token array, device_put, prefill_one
+    "engine.prefill.insert",        # the slot cache's .at[slot].set
+    "engine.prefill.readback",      # the first token's argmax to the host
+    "engine.decode",                # _step
+    "engine.decode.dispatch",       # token array, device_put, decode step
+    "engine.decode.readback",       # the tokens' argmax to the host
+    "engine.decode.bookkeep",       # per-slot loop, egress, KV occupancy
+    "engine.flush",                 # _flush_telemetry
+    "engine.flush.observe",         # the plane's or sidecar's observe_batch
+    "engine.flush.advance",         # the sidecar's advance
+)
 
 
 @dataclass
@@ -49,9 +74,6 @@ class EngineConfig:
     control: str = "instant"
     dpu: "object | None" = None      # repro.dpu.DPUParams override
     dpu_seed: int = 0                # sidecar wire RNG (XORed with node)
-    # observe-only causal tracing (repro.obs): spans for every finding /
-    # policy decision / bus exchange / actuation on this engine's loop
-    trace: bool = False
 
 
 def slot_cache(model: Model, slots: int, max_seq: int) -> dict:
@@ -101,21 +123,6 @@ class InferenceEngine:
             self._sink = self.dpu
         elif self.plane is not None and self.plane.controller is not None:
             self.plane.controller.engine = self
-        # observability (observe-only; engine runs have no FaultSpec, so
-        # incidents open on the first finding and never auto-close)
-        self.tracer = None
-        self.recorder = None
-        if self.cfg.trace and self.plane is not None:
-            from repro.obs import FlightRecorder, Tracer
-            self.recorder = FlightRecorder()
-            self.tracer = Tracer(recorder=self.recorder)
-            if self.dpu is not None:
-                self.dpu.attach_tracer(self.tracer, "primary",
-                                       recorder=self.recorder)
-            else:
-                self.plane.tracer = self.tracer
-                self.plane.trace_source = "engine"
-                self.plane.recorder = self.recorder
         # the engine lives on the device that holds its params: caches and
         # step inputs are placed beside them, so replicas whose params sit
         # on different chips each run on their own chip
@@ -137,7 +144,10 @@ class InferenceEngine:
         # telemetry back-pressure knob: emit low-priority samples (KV
         # occupancy) every Nth step; throttle_telemetry doubles the stride
         self.telemetry_stride = 1
-        self.stats = {"steps": 0, "tokens": 0, "prefills": 0}
+        # prefill_positions counts the bucket positions the prefill
+        # programs computed, prefill_tokens the prompt tokens among them
+        self.stats = {"steps": 0, "tokens": 0, "prefills": 0,
+                      "prefill_tokens": 0, "prefill_positions": 0}
         # telemetry taps accumulate columnar rows; one batch per step goes
         # to the plane (the engine feeds the same line-rate path as the sim)
         self._pending = EventBatchBuilder()
@@ -147,11 +157,6 @@ class InferenceEngine:
     # ------------------------------------------------------------------
 
     def apply_action(self, action: str, node: int, detail: dict) -> bool:
-        if self.tracer is not None:
-            # the live engine has no fault oracle, so no recovery flip —
-            # the apply is recorded on the open incident's span tree
-            self.tracer.on_apply(action, node, self.clock, False, False,
-                                 "engine")
         if action == "inflight_remap":
             self.sched.set_continuous(True)
             return True
@@ -193,14 +198,17 @@ class InferenceEngine:
                               node=self.cfg.node, **kw)
 
     def _flush_telemetry(self) -> None:
-        if self.plane is None:
-            return
-        if len(self._pending):
-            batch = self._pending.build(sort=True)
-            self._pending.clear()
-            self._sink.observe_batch(batch)
-        if self.dpu is not None:
-            self.dpu.advance(self.clock)
+        with _span("engine.flush", events=len(self._pending)):
+            if self.plane is None:
+                return
+            if len(self._pending):
+                batch = self._pending.build(sort=True)
+                self._pending.clear()
+                with _span("engine.flush.observe"):
+                    self._sink.observe_batch(batch)
+            if self.dpu is not None:
+                with _span("engine.flush.advance"):
+                    self.dpu.advance(self.clock)
 
     def _prefill_fn(self, bucket: int):
         if bucket not in self._prefill_jit:
@@ -214,46 +222,55 @@ class InferenceEngine:
         return self._prefill_jit[bucket]
 
     def _admit_loop(self) -> None:
-        while True:
-            if not self.sched.queue:
-                break
-            head = self.sched.queue[0]
-            need = head.prompt_len + head.max_new_tokens
-            if not self.pool.can_admit(need):
-                # paper §5: early KV eviction under pressure
-                if self.pool.evict_lru() is None:
+        with _span("engine.admit"):
+            while True:
+                if not self.sched.queue:
                     break
-                continue
-            got = self.sched.admit(self.clock)
-            if got is None:
-                break
-            slot, req = got
-            self.pool.allocate(req.req_id, need)
-            self._prefill(slot, req)
+                head = self.sched.queue[0]
+                need = head.prompt_len + head.max_new_tokens
+                if not self.pool.can_admit(need):
+                    # paper §5: early KV eviction under pressure
+                    if self.pool.evict_lru() is None:
+                        break
+                    continue
+                got = self.sched.admit(self.clock)
+                if got is None:
+                    break
+                slot, req = got
+                self.pool.allocate(req.req_id, need)
+                self._prefill(slot, req)
 
     def _prefill(self, slot: int, req: ServeRequest) -> None:
         bucket = self.sched.bucket_len(req.prompt_len)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, -req.prompt_len:] = req.prompt    # left-pad into bucket
-        self._emit(EventKind.H2D_XFER, device=slot % 4,
-                   size=int(toks.size * 4), flow=req.req_id)
-        self._emit(EventKind.DISPATCH, device=slot % 4)
-        logits, cache = self._prefill_fn(bucket)(
-            self.params, jax.device_put(toks, self.device))
-        if self.on_logits is not None:
-            self.on_logits({0: req}, toks, logits[:, -1])
-        # first-token logits return to the host (pairs with the dispatch)
-        self._emit(EventKind.D2H_XFER, device=slot % 4,
-                   size=int(logits.size * 4), flow=req.req_id)
-        # write the per-slot cache
-        self.slot_cache = jax.tree.map(
-            lambda full, one: full.at[slot].set(one[...]),
-            self.slot_cache, cache)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        req.tokens_out = 0
-        req.first_token = -1.0
-        self._slot_next_token[slot] = nxt
-        self.stats["prefills"] += 1
+        with _span("engine.prefill", req_id=req.req_id, slot=slot,
+                   bucket=bucket):
+            with _span("engine.prefill.dispatch"):
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, -req.prompt_len:] = req.prompt   # left-pad
+                self._emit(EventKind.H2D_XFER, device=slot % 4,
+                           size=int(toks.size * 4), flow=req.req_id)
+                self._emit(EventKind.DISPATCH, device=slot % 4)
+                logits, cache = self._prefill_fn(bucket)(
+                    self.params, jax.device_put(toks, self.device))
+            if self.on_logits is not None:
+                self.on_logits({0: req}, toks, logits[:, -1])
+            # first-token logits return to the host (pairs with the
+            # dispatch)
+            self._emit(EventKind.D2H_XFER, device=slot % 4,
+                       size=int(logits.size * 4), flow=req.req_id)
+            # write the per-slot cache
+            with _span("engine.prefill.insert"):
+                self.slot_cache = jax.tree.map(
+                    lambda full, one: full.at[slot].set(one[...]),
+                    self.slot_cache, cache)
+            with _span("engine.prefill.readback"):
+                nxt = int(jnp.argmax(logits[0, -1]))
+            req.tokens_out = 0
+            req.first_token = -1.0
+            self._slot_next_token[slot] = nxt
+            self.stats["prefills"] += 1
+            self.stats["prefill_tokens"] += req.prompt_len
+            self.stats["prefill_positions"] += bucket
 
     # ------------------------------------------------------------------
     # decode loop
@@ -286,20 +303,31 @@ class InferenceEngine:
 
     def _step(self) -> None:
         slots = sorted(self.sched.running)
-        toks = np.zeros((self.cfg.max_slots, 1, 1), np.int32)
-        for s in slots:
-            toks[s, 0, 0] = self._slot_next_token.get(s, 0)
-        self._emit(EventKind.DISPATCH, device=0)
-        logits, new_cache = self._decode_vmapped(
-            self.params, jax.device_put(toks, self.device), self.slot_cache)
-        self.slot_cache = new_cache
-        if self.on_logits is not None:
-            self.on_logits({s: self.sched.running[s] for s in slots},
-                           toks[:, 0], logits[:, 0, -1])
-        self._emit(EventKind.D2H_XFER, device=0,
-                   size=len(slots) * 4)
-        self.stats["steps"] += 1
-        nxt = np.asarray(jnp.argmax(logits[:, 0, -1], axis=-1))
+        with _span("engine.decode", step=self.stats["steps"],
+                   slots=len(slots)):
+            with _span("engine.decode.dispatch"):
+                toks = np.zeros((self.cfg.max_slots, 1, 1), np.int32)
+                for s in slots:
+                    toks[s, 0, 0] = self._slot_next_token.get(s, 0)
+                self._emit(EventKind.DISPATCH, device=0)
+                logits, new_cache = self._decode_vmapped(
+                    self.params, jax.device_put(toks, self.device),
+                    self.slot_cache)
+                self.slot_cache = new_cache
+            if self.on_logits is not None:
+                self.on_logits({s: self.sched.running[s] for s in slots},
+                               toks[:, 0], logits[:, 0, -1])
+            self._emit(EventKind.D2H_XFER, device=0,
+                       size=len(slots) * 4)
+            self.stats["steps"] += 1
+            with _span("engine.decode.readback"):
+                nxt = np.asarray(jnp.argmax(logits[:, 0, -1], axis=-1))
+            with _span("engine.decode.bookkeep"):
+                self._bookkeep(slots, nxt)
+
+    def _bookkeep(self, slots: list[int], nxt: np.ndarray) -> None:
+        """After a decode step: advance each running slot, release the
+        finished ones, and queue the step's telemetry."""
         eg_flow: list[int] = []
         eg_meta: list[int] = []
         for s in slots:
