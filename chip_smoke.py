@@ -156,8 +156,8 @@ def check_params_are_arguments(engine) -> None:
     """The decode program's ``main`` takes every weight as an argument:
     none is folded into the program as a constant."""
     toks = jax.ShapeDtypeStruct((engine.cfg.max_slots, 1, 1), jnp.int32)
-    text = engine._decode_vmapped.lower(engine.params, toks,
-                                        engine.slot_cache).as_text()
+    text = engine._decode.lower(engine.params, toks,
+                                engine.slot_cache).as_text()
     main = next(line for line in text.splitlines()
                 if "func.func public @main" in line)
     n_args = len(jax.tree.leaves((engine.params, toks, engine.slot_cache)))
@@ -178,8 +178,15 @@ def warm_up(engine, buckets) -> None:
     dev = engine.device
     toks = jax.device_put(np.zeros((engine.cfg.max_slots, 1, 1), np.int32),
                           dev)
-    first, step = _timed(engine._decode_vmapped, engine.params, toks,
-                         engine.slot_cache)
+    cache = [engine.slot_cache]
+
+    def decode():
+        # the step donates the cache it is given: pass on the one it returns
+        logits, cache[0] = engine._decode(engine.params, toks, cache[0])
+        return logits
+
+    first, step = _timed(decode)
+    engine.slot_cache = cache[0]
     print(f"[compile] decode ({engine.cfg.max_slots} slots x "
           f"{engine.cfg.max_seq}): first call {first:.2f} s, then "
           f"{step * 1e3:.2f} ms/step (median of 5)")

@@ -1,5 +1,5 @@
 """Unified model facade: build_model(cfg) -> Model with init / loss /
-prefill / decode_step / init_cache / input_specs.
+prefill / decode_step / decode_step_inplace / init_cache / input_specs.
 
 The same entry points serve four consumers:
   - CPU smoke tests (reduced configs, no sharding),
@@ -182,6 +182,16 @@ class Model:
         return self.prefill(params, tokens, cache, shard) \
             if self.cfg.family == "encdec" and "enc_out" not in cache \
             else self._step(params, tokens, cache, shard)
+
+    def decode_step_inplace(self, params: dict, tokens: jax.Array,
+                            cache: dict) -> tuple[jax.Array, dict]:
+        """One decode step for a batch of slots over a layer-major slot
+        cache (``transformer.decoder_decode_slots``): tokens (B, 1, 1) ->
+        logits (B, 1, 1, V), new cache.  Each slot's new keys and values
+        are written at its own position, in place where the caller
+        donates the cache.  For the per-layer K/V cache of
+        ``decoder_fwd`` (dense, moe, vlm) only."""
+        return T.decoder_decode_slots(params, self.cfg, tokens, cache)
 
     def _step(self, params, tokens, cache, shard):
         cfg = self.cfg

@@ -18,6 +18,7 @@ dry-run.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any, Callable
 
@@ -27,6 +28,7 @@ import jax.numpy as jnp
 from repro.models.config import ModelConfig
 from repro.models import ssm as S
 from repro.models.layers import (
+    apply_rope,
     attention_fwd,
     attention_init,
     dense_init,
@@ -203,6 +205,102 @@ def decoder_fwd(params: dict, cfg: ModelConfig, tokens: jax.Array,
             else params["lm_head"])
     logits = shard.act(x @ head.astype(x.dtype), "logits")
     return logits, aux, new_cache
+
+
+# ----------------------------------------------------------------------
+# batched decode over a layer-major slot cache, written in place
+# ----------------------------------------------------------------------
+
+def _slot_layer_step(lp: dict, x, pos, kpos, ck, cv, *, cfg: ModelConfig):
+    """One decoder layer for one slot's next token.
+
+    x (1, 1, d); pos () the token's position; kpos (S,) the positions the
+    ring held before this step (-1 = empty); ck/cv (Hkv, S, hd) the layer's
+    cache before this step.  The token attends the ring's live positions,
+    less the one it is about to overwrite, and itself as one extra score
+    in the same float32 softmax.  Returns (x, k (Hkv, hd), v (Hkv, hd)).
+    """
+    hd, hkv = cfg.hd, cfg.n_kv_heads
+    g = cfg.n_heads // hkv
+    p = lp["attn"]
+    h = rmsnorm(lp["ln1"], x, cfg.norm_eps)
+    q = (h @ p["wq"]).reshape(1, 1, cfg.n_heads, hd)
+    k = (h @ p["wk"]).reshape(1, 1, hkv, hd)
+    v = (h @ p["wv"]).reshape(1, 1, hkv, hd)
+    if cfg.qk_norm:
+        q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
+        k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
+    q = apply_rope(q, pos[None], cfg.rope_theta)
+    k = apply_rope(k, pos[None], cfg.rope_theta)
+    k = k[0, 0].astype(ck.dtype)
+    v = v[0, 0].astype(cv.dtype)
+    qg = q[0, 0].reshape(hkv, g, hd)
+    s = ck.shape[1]
+    live = (kpos >= 0) & (kpos <= pos) & (jnp.arange(s) != pos % s)
+    if cfg.swa_window > 0:
+        live &= kpos > pos - cfg.swa_window
+    scores = jnp.concatenate([
+        jnp.einsum("hgd,hsd->hgs", qg, ck,
+                   preferred_element_type=jnp.float32),
+        jnp.einsum("hgd,hd->hg", qg, k,
+                   preferred_element_type=jnp.float32)[..., None]], axis=-1)
+    scores = scores / math.sqrt(hd)
+    live = jnp.concatenate([live, jnp.ones((1,), bool)])
+    probs = jax.nn.softmax(jnp.where(live, scores, jnp.float32(-1e30)),
+                           axis=-1).astype(cv.dtype)
+    out = (jnp.einsum("hgs,hsd->hgd", probs[..., :s], cv,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("hg,hd->hgd", probs[..., s], v,
+                        preferred_element_type=jnp.float32))
+    out = out.reshape(1, 1, cfg.n_heads * hd).astype(x.dtype)
+    x = x + out @ p["wo"]
+    h = rmsnorm(lp["ln2"], x, cfg.norm_eps)
+    if cfg.is_moe:
+        out, _ = moe_fwd(lp["moe"], cfg, h)
+    else:
+        out = mlp_fwd(lp["mlp"], h)
+    return x + out, k, v
+
+
+def decoder_decode_slots(params: dict, cfg: ModelConfig, tokens: jax.Array,
+                         cache: dict) -> tuple[jax.Array, dict]:
+    """One decode step for every slot of a layer-major slot cache.
+
+    tokens (B, 1, 1); cache {"k"/"v": (L, B, Hkv, S, hd), "kpos": (B, S),
+    "pos": (B,)}.  The scan runs over layers, each layer ``vmap``s the
+    single-slot step over slots and yields only the new keys and values;
+    one ``dynamic_update_slice`` per slot then writes them at
+    ``pos % S``, in place where the caller donates the cache.
+    Returns (logits (B, 1, 1, V), new cache).
+    """
+    b = tokens.shape[0]
+    s = cache["k"].shape[3]
+    pos, kpos = cache["pos"], cache["kpos"]
+    x = params["embed"].astype(dtype_of(cfg))[tokens]        # (B, 1, 1, d)
+    step = jax.vmap(partial(_slot_layer_step, cfg=cfg),
+                    in_axes=(None, 0, 0, 0, 0, 0))
+
+    def body(x, inp):
+        lp, ck, cv = inp
+        x, nk, nv = step(lp, x, pos, kpos, ck, cv)
+        return x, (nk, nv)
+
+    x, (nk, nv) = scan_layers(body, x, (params["layers"], cache["k"],
+                                        cache["v"]), cfg)
+    x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
+    head = (params["embed"].T if cfg.tie_embeddings
+            else params["lm_head"])
+    logits = x @ head.astype(x.dtype)
+
+    ring = pos % s
+    ck, cv = cache["k"], cache["v"]
+    for i in range(b):        # slot i's (L, Hkv, hd) as (L, 1, Hkv, 1, hd)
+        at = (0, i, 0, ring[i], 0)
+        ck = jax.lax.dynamic_update_slice(ck, nk[:, i][:, None, :, None], at)
+        cv = jax.lax.dynamic_update_slice(cv, nv[:, i][:, None, :, None], at)
+    return logits, {"k": ck, "v": cv,
+                    "kpos": kpos.at[jnp.arange(b), ring].set(pos),
+                    "pos": pos + 1}
 
 
 # ----------------------------------------------------------------------

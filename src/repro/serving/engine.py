@@ -1,13 +1,16 @@
 """InferenceEngine: continuous-batching serving loop with the DPU-analog
 telemetry plane wired through it (the paper's architecture, live).
 
-Per-slot KV caches are a stacked pytree; the decode step is the Model's
-single-sequence step vmapped over slots, so every slot carries its own
-position/ring state (true continuous batching).  Telemetry taps emit the
-exact event schema the detectors consume: INGRESS on request arrival, H2D
-around prefill feeds, DISPATCH per step, D2H per step, EGRESS per token,
-QUEUE_SAMPLE per scheduler tick — and the engine implements EngineControls
-so the mitigation controller can close the loop (§5).
+Every slot carries its own position/ring state (true continuous batching).
+Where the model's cache is the per-layer K/V cache of ``decoder_fwd``, the
+slot cache is layer-major and the decode step writes one position per slot
+in place (``Model.decode_step_inplace``, the cache donated); any other cache
+is a stacked pytree that the Model's single-sequence step, vmapped over
+slots, decodes.  Telemetry taps emit the exact event schema the detectors
+consume: INGRESS on request arrival, H2D around prefill feeds, DISPATCH per
+step, D2H per step, EGRESS per token, QUEUE_SAMPLE per scheduler tick — and
+the engine implements EngineControls so the mitigation controller can close
+the loop (§5).
 
 Each phase of the loop is a host span (``SPANS``) in the profiler's own
 trace, so a traced run puts the device's idle time down to engine phases on
@@ -76,19 +79,56 @@ class EngineConfig:
     dpu_seed: int = 0                # sidecar wire RNG (XORed with node)
 
 
+_LAYER_KV = frozenset(("k", "v", "kpos", "pos"))
+
+
+def decodes_in_place(model: Model) -> bool:
+    """Whether the model's cache is ``decoder_fwd``'s per-layer K/V cache
+    and nothing else (dense, moe, vlm): such a model decodes in place."""
+    return set(jax.eval_shape(lambda: model.init_cache(1, 1))) == _LAYER_KV
+
+
+def _slot_kv(k: jax.Array) -> jax.Array:
+    """A prefill's K or V, (L, 1, S, Hkv, hd), in the slot cache's
+    layer-major layout (L, 1, Hkv, S, hd)."""
+    return jnp.swapaxes(k, 2, 3)
+
+
 def slot_cache(model: Model, slots: int, max_seq: int) -> dict:
-    """Stacked per-slot caches: every leaf of ``init_cache(1, max_seq)``
-    with a leading slot axis."""
+    """The engine's cache for ``slots`` sequences.  For a model that
+    decodes in place: K/V (L, slots, Hkv, S, hd), kpos (slots, S), pos
+    (slots,); else every leaf of ``init_cache(1, max_seq)`` with a leading
+    slot axis."""
+    one = model.init_cache(1, max_seq)
+    if decodes_in_place(model):
+        return {"k": jnp.repeat(_slot_kv(one["k"]), slots, axis=1),
+                "v": jnp.repeat(_slot_kv(one["v"]), slots, axis=1),
+                "kpos": jnp.repeat(one["kpos"][None], slots, axis=0),
+                "pos": jnp.repeat(one["pos"][None], slots, axis=0)}
     return jax.tree.map(
-        lambda a: jnp.broadcast_to(a, (slots,) + a.shape).copy(),
-        model.init_cache(1, max_seq))
+        lambda a: jnp.broadcast_to(a, (slots,) + a.shape).copy(), one)
+
+
+def insert(full: dict, one: dict, slot: int) -> dict:
+    """The slot cache with ``slot`` replaced by a prefill's cache ``one``
+    (``init_cache(1, max_seq)`` as the prefill left it)."""
+    if set(full) == _LAYER_KV:
+        return {"k": full["k"].at[:, slot:slot + 1].set(_slot_kv(one["k"])),
+                "v": full["v"].at[:, slot:slot + 1].set(_slot_kv(one["v"])),
+                "kpos": full["kpos"].at[slot].set(one["kpos"]),
+                "pos": full["pos"].at[slot].set(one["pos"])}
+    return jax.tree.map(lambda f, o: f.at[slot].set(o[...]), full, one)
 
 
 def decode_fn(model: Model):
-    """The engine's jitted decode step, vmapped over slots:
-    (params, tokens (slots, 1, 1), slot cache) -> (logits, slot cache).
-    The params are an argument, not a closed-over constant, so the
-    weights stay out of the compiled program and its cache key."""
+    """The engine's jitted decode step: (params, tokens (slots, 1, 1),
+    slot cache) -> (logits (slots, 1, 1, V), slot cache).  In place, with
+    the cache donated, where the model decodes in place; else the
+    single-sequence step vmapped over slots.  The params are an argument,
+    not a closed-over constant, so the weights stay out of the compiled
+    program and its cache key."""
+    if decodes_in_place(model):
+        return jax.jit(model.decode_step_inplace, donate_argnums=(2,))
     return jax.jit(jax.vmap(model.decode_step, in_axes=(None, 0, 0)))
 
 
@@ -131,7 +171,8 @@ class InferenceEngine:
         with jax.default_device(self.device):
             self.slot_cache = slot_cache(model, self.cfg.max_slots,
                                          self.cfg.max_seq)
-        self._decode_vmapped = decode_fn(model)
+        self._decode = decode_fn(model)
+        self._inplace = decodes_in_place(model)
         self._prefill_jit: dict[int, callable] = {}
         # observer of every logits row the engine computes:
         # on_logits({row: request}, consumed tokens (rows, n), logits
@@ -145,9 +186,11 @@ class InferenceEngine:
         # occupancy) every Nth step; throttle_telemetry doubles the stride
         self.telemetry_stride = 1
         # prefill_positions counts the bucket positions the prefill
-        # programs computed, prefill_tokens the prompt tokens among them
+        # programs computed, prefill_tokens the prompt tokens among them;
+        # inplace_steps the decode steps that wrote the cache in place
         self.stats = {"steps": 0, "tokens": 0, "prefills": 0,
-                      "prefill_tokens": 0, "prefill_positions": 0}
+                      "prefill_tokens": 0, "prefill_positions": 0,
+                      "inplace_steps": 0}
         # telemetry taps accumulate columnar rows; one batch per step goes
         # to the plane (the engine feeds the same line-rate path as the sim)
         self._pending = EventBatchBuilder()
@@ -260,9 +303,7 @@ class InferenceEngine:
                        size=int(logits.size * 4), flow=req.req_id)
             # write the per-slot cache
             with _span("engine.prefill.insert"):
-                self.slot_cache = jax.tree.map(
-                    lambda full, one: full.at[slot].set(one[...]),
-                    self.slot_cache, cache)
+                self.slot_cache = insert(self.slot_cache, cache, slot)
             with _span("engine.prefill.readback"):
                 nxt = int(jnp.argmax(logits[0, -1]))
             req.tokens_out = 0
@@ -310,16 +351,17 @@ class InferenceEngine:
                 for s in slots:
                     toks[s, 0, 0] = self._slot_next_token.get(s, 0)
                 self._emit(EventKind.DISPATCH, device=0)
-                logits, new_cache = self._decode_vmapped(
+                # a donated cache is gone once passed: rebind at once
+                logits, self.slot_cache = self._decode(
                     self.params, jax.device_put(toks, self.device),
                     self.slot_cache)
-                self.slot_cache = new_cache
             if self.on_logits is not None:
                 self.on_logits({s: self.sched.running[s] for s in slots},
                                toks[:, 0], logits[:, 0, -1])
             self._emit(EventKind.D2H_XFER, device=0,
                        size=len(slots) * 4)
             self.stats["steps"] += 1
+            self.stats["inplace_steps"] += self._inplace
             with _span("engine.decode.readback"):
                 nxt = np.asarray(jnp.argmax(logits[:, 0, -1], axis=-1))
             with _span("engine.decode.bookkeep"):

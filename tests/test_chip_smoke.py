@@ -158,10 +158,8 @@ def test_closed_over_params_are_caught(cs):
     """The check fails for a decode step that closes over its weights."""
     engine = build_engine(ARCHS["qwen3-0.6b"].reduced(), slots=2,
                           max_seq=32)
-    params, step = engine.params, engine.model.decode_step
-    engine._decode_vmapped = jax.jit(jax.vmap(
-        lambda _, tok, cache: step(params, tok, cache),
-        in_axes=(None, 0, 0)))
+    params, step = engine.params, engine.model.decode_step_inplace
+    engine._decode = jax.jit(lambda _, tok, cache: step(params, tok, cache))
     with pytest.raises(AssertionError, match="does not take the weights"):
         cs.check_params_are_arguments(engine)
 

@@ -103,8 +103,9 @@ def test_engine_decode_qwen3_fits_one_chip(one_chip):
     toks = _spec(one_chip, (8, 1, 1), jnp.int32)
     compiled = decode_fn(model).lower(params, toks, cache).compile()
     mem = compiled.memory_analysis()
+    # the donated cache is written in place: its output is the argument
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes)
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert total < V5E_HBM_BYTES, total
     # the weights arrive as arguments (1.2 GB of bf16), not as constants
     n_param = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
