@@ -30,8 +30,6 @@ for _p in (ROOT / "src", ROOT):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
 
-import jax  # noqa: E402
-
 from benchmarks.chip import run, spec  # noqa: E402
 
 
@@ -45,14 +43,13 @@ def main(argv: list[str] | None = None) -> int:
     cell = spec.workload(bench, args.workload)
     cfg = spec.config(cell["config"])
     mix = spec.traffic(cell["traffic"])
-    device = jax.devices()[0]
-    if device.platform != "tpu" or cell["chips"] != 1:
-        print("control: needs a TPU and a one-chip cell", file=sys.stderr)
+    devices = run.devices_for(cell, args.workload, "control")
+    if devices is None:
         return 2
     run.use_compile_cache()
     for seed in (int(s) for s in args.seeds.split(",")):
         res = run.run_cell(cell, cfg, mix, seed=seed, seconds=args.seconds,
-                           trace=False, device=device, bench=bench,
+                           trace=False, devices=devices, bench=bench,
                            t_start=time.perf_counter(), control=True,
                            log=lambda *a: print(*a, file=sys.stderr))
         for name, c in res["checks"].items():

@@ -1,6 +1,9 @@
 """Kernels (the decode program): the least time of the decode steps'
 work, max(operations / peak rate, bytes / peak bandwidth), counted from the
-live lengths, over the decode program's device time, in percent."""
+live lengths by the configuration's architecture, over the decode
+program's device time, in percent.  A step runs on one replica's chip, so
+both sides are per chip: the mean least time of a step over the mean
+device time of a call, over the steps and calls of every chip."""
 
 from benchmarks.chip import work
 from benchmarks.chip.stats import window_steps
@@ -17,6 +20,6 @@ def read(run):
     steps = window_steps(run.record)
     if not calls or not steps:
         return None
-    least = [work.least_time(*work.decode_step(run.config, s.contexts),
-                             run.peak) for s in steps]
+    least = [work.least_time(*run.arch.decode_step(run.config, s), run.peak)
+             for s in steps]
     return 100.0 * (sum(least) / len(least)) / (sec / calls)

@@ -1,5 +1,6 @@
 """Model step, decode: device time of the decode program in the trace (the
-engine's vmapped ``decode_step``), per call."""
+engine's ``decode_step*`` programs), per call, over the calls of every
+chip in use."""
 
 PROGRAM = "decode_step"
 

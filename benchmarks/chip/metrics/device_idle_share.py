@@ -1,5 +1,5 @@
-"""Device: the share of the traced window in which no operation ran on the
-chip, in percent."""
+"""Device: the share of the traced window in which no operation ran on a
+chip, in percent, the mean over the chips in use."""
 
 
 def read(run):

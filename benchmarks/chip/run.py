@@ -4,29 +4,35 @@
       --seconds <run_seconds> --trace <0|1>
 
 From the root of a checkout.  The cell (``BENCHMARK.json``) names a
-configuration (``configs/<name>.json``) and a traffic mix
-(``traffic/<name>.json``), and one chip: the harness serves one engine.
-The run
+configuration (``configs/<name>.json``, whose ``arch`` names its
+architecture module, ``archs/<arch>.py``), a traffic mix
+(``traffic/<name>.json``) and its chips: that many one-chip replicas, one
+engine on each device, behind ``ReplicaSet`` under the mix's ``router``
+policy (one chip: the one engine, no router).  The run
 
-  1. makes the weights from the seed on the chip in one jitted call and
-     builds an ``InferenceEngine`` (DPU control, mitigation on);
+  1. makes the weights from the seed on each chip in one jitted call and
+     builds an ``InferenceEngine`` there (DPU control, mitigation on);
   2. warms up the decode step and the prefill buckets this traffic uses,
      with JAX's persistent compilation cache in ``<checkout>/.jax_cache``
      (or ``$JAX_COMPILATION_CACHE_DIR``); set-up ends when the window opens;
   3. drives the open loop on the host clock for ``--seconds``
-     (``serve_loop``), traced by the profiler with ``--trace 1``;
-  4. reads the peak device memory, frees the engine, and compares a
-     seeded sample of the finished requests, the longest among them, with
-     the float32 reference (``reference``): the widest gap by which a
-     served token's reference logit lies below the reference's best;
+     (``serve_loop``), traced by the profiler with ``--trace 1``: the
+     whole window, or its last ``trace_seconds`` where the mix names them
+     (a trace of every chip over the whole window takes too long to
+     read);
+  4. reads the peak memory of each device, frees the engines, and
+     compares a seeded sample of the finished requests, the longest among
+     them and one of every replica, with the float32 reference
+     (``reference``): the widest gap by which a served token's reference
+     logit lies below the reference's best;
   5. prints, as the last line of stdout, one JSON object: ``correct``,
      ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics,
      or with ``--trace 1`` its per-layer ones), ``device``, ``breakdown``
      in a traced run, and last ``checks``: each compared number beside
      its limit, also the last lines of stderr.
 
-Without a TPU, or for a cell that asks for other than one chip, it exits
-2 and prints no result.
+Without a TPU, or on a host with fewer devices than the cell's chips, it
+exits 2 and prints no result.
 """
 
 from __future__ import annotations
@@ -36,7 +42,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import random  # noqa: E402
@@ -57,40 +62,36 @@ from benchmarks.chip import reference, spec, stats, traffic, weights  # noqa: E4
 from benchmarks.chip import xtrace  # noqa: E402
 from benchmarks.chip.serve_loop import OpenLoop  # noqa: E402
 
-# configuration keys (Hugging Face names) -> the program's ModelConfig
-PROGRAM_KEYS = {
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff", "vocab_size": "vocab",
-    "head_dim": "head_dim", "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings",
-    "qk_norm": "qk_norm",
-}
 PAGE = 16
 
 
-def program_config(cfg: dict):
-    """The registry's entry with the sizes of the configuration file."""
-    from repro.configs import ARCHS
-    kw = {v: cfg[k] for k, v in PROGRAM_KEYS.items()}
-    kw["rope_theta"] = float(kw["rope_theta"])
-    kw["swa_window"] = cfg.get("sliding_window") or 0
-    kw["dtype"] = cfg["torch_dtype"]
-    return dataclasses.replace(ARCHS[cfg["registry"]], **kw)
-
-
-def build_engine(cfg: dict, seed: int, device):
+def build_engine(cfg: dict, seed: int, device, node: int = 0):
+    """One engine on ``device``: the program's model of the configuration's
+    architecture, weights made from the seed."""
     from repro.models import build_model
     from repro.serving import EngineConfig, InferenceEngine
+    arch = spec.arch(cfg["arch"])
     serving = cfg["serving"]
-    model = build_model(program_config(cfg))
-    params = weights.make_params(cfg, seed, device)
+    model = build_model(arch.program_config(cfg))
+    params = weights.make_params(cfg, seed, device, arch)
     weights.check_layout(params, model)
     return InferenceEngine(model, params, EngineConfig(
         max_slots=serving["slots"], max_seq=serving["max_seq"],
         page_size=PAGE, n_pages=serving["max_seq"] * serving["slots"]
         // PAGE, control=serving["control"],
-        mitigate=serving["mitigate"]))
+        mitigate=serving["mitigate"], node=node))
+
+
+def build_engines(cfg: dict, seed: int, devices: list) -> list:
+    """One engine per device, each with the same seed's weights."""
+    return [build_engine(cfg, seed, d, node=i) for i, d in enumerate(devices)]
+
+
+def open_loop(engines: list, mix: dict, seed: int, spans: bool = False):
+    """The open loop over the engines, behind the mix's router where
+    there are several."""
+    return OpenLoop(engines, spans=spans, router=mix.get("router"),
+                    seed=seed)
 
 
 class CompileCounter:
@@ -107,15 +108,20 @@ class CompileCounter:
 
 
 def sample(requests: list, seed: int, k: int) -> list:
-    """k finished requests drawn from the seed: the longest, and the rest
-    at random."""
+    """k finished requests drawn from the seed: the longest, one of every
+    replica that served any, and the rest at random."""
     done = [r for r in requests if r.finished]
     if not done:
         return []
     longest = max(done, key=lambda r: (len(r.prompt) + len(r.tokens), -r.rid))
     rest = [r for r in done if r is not longest]
     random.Random(seed ^ 0x5EED).shuffle(rest)
-    return [longest] + rest[:k - 1]
+    picked = [longest]
+    for rep in sorted({r.replica for r in rest} - {longest.replica}):
+        picked.append(next(r for r in rest if r.replica == rep))
+    chosen = {id(r) for r in picked}
+    more = [r for r in rest if id(r) not in chosen]
+    return picked + more[:max(k - len(picked), 0)]
 
 
 def judge(gap: float, unanswered: int, rows: int, limit) -> tuple:
@@ -166,32 +172,42 @@ def use_compile_cache() -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
 
-def log_monitor(eng, log) -> None:
-    tel = eng.plane.report() if eng.plane is not None else {}
-    log(f"[monitor] {tel.get('events', 0)} events, findings "
-        f"{tel.get('findings_by_row', {})}, actions "
-        f"{[a for _, a, _ in tel.get('actions', [])]}")
+def log_monitor(engines: list, log) -> None:
+    for i, eng in enumerate(engines):
+        tel = eng.plane.report() if eng.plane is not None else {}
+        who = f" replica {i}:" if len(engines) > 1 else ""
+        log(f"[monitor]{who} {tel.get('events', 0)} events, findings "
+            f"{tel.get('findings_by_row', {})}, actions "
+            f"{[a for _, a, _ in tel.get('actions', [])]}")
+
+
+def peak_memory(devices: list) -> list[int]:
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+            for d in devices]
 
 
 def run_cell(cell: dict, cfg: dict, mix: dict, *, seed: int, seconds: float,
-             trace: bool, device, bench: dict, t_start: float,
+             trace: bool, devices: list, bench: dict, t_start: float,
              control: bool = False, keep_trace: str | None = None,
              log=print) -> dict:
     """One run; returns the result object (the last line of stdout).
+    ``devices`` holds at least the cell's chips; the first ``chips`` serve.
 
     With ``control`` the fp8-weight reference is put in the served path's
     place: its first choices are judged against the same limit by the
     same rule, so ``correct`` has to come out false; the served path's
     own judgement is kept under ``served``."""
+    devices = list(devices[:cell["chips"]])
+    device = devices[0]
     kind = device.device_kind
     peak = spec.peaks(kind) if device.platform == "tpu" else {}
     t_devices = time.perf_counter() - t_start
-    engine = build_engine(cfg, seed, device)
-    jax.block_until_ready(engine.params)
+    engines = build_engines(cfg, seed, devices)
+    jax.block_until_ready([e.params for e in engines])
     t_engine = time.perf_counter() - t_start
-    loop = OpenLoop(engine, spans=trace)
+    loop = open_loop(engines, mix, seed, spans=trace)
     lens = traffic.prompt_lengths(mix, seconds)
-    buckets = sorted({engine.sched.bucket_len(n) for n in lens})
+    buckets = sorted({engines[0].sched.bucket_len(n) for n in lens})
     loop.warm_up(buckets)
     log(f"[setup] devices and imports by {t_devices:.3f} s, weights and "
         f"engine by {t_engine:.3f} s, warm-up of prefill buckets "
@@ -201,20 +217,27 @@ def run_cell(cell: dict, cfg: dict, mix: dict, *, seed: int, seconds: float,
     counter = CompileCounter()
     holder = None
     if trace:
-        with xtrace.traced(keep_trace) as holder:
+        last = mix.get("trace_seconds")
+        part = (seconds - last, seconds) if last and last < seconds \
+            else None
+        with xtrace.traced(keep_trace, part) as holder:
             counter.on = True
             setup_s = time.perf_counter() - t_start
             rec = loop.run(requests, seconds, mix["drain"])
             counter.on = False
+            t = time.perf_counter()
+        log(f"[trace] {holder.summary.window_s:.3f} s traced on "
+            f"{holder.summary.devices} devices; stopped and read in "
+            f"{time.perf_counter() - t:.3f} s")
     else:
         counter.on = True
         setup_s = time.perf_counter() - t_start
         rec = loop.run(requests, seconds, mix["drain"])
         counter.on = False
     summary = holder.summary if holder is not None else None
-    memory = (device.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    memory = peak_memory(devices)
     run = stats.Run(record=rec, setup_s=setup_s, config=cfg, peak=peak,
-                    trace=summary)
+                    trace=summary, chips=len(devices))
     kind_key = "per_layer" if trace else "end_to_end"
     metrics = {}
     for m in spec.metrics(bench, cell["name"], kind_key):
@@ -246,16 +269,26 @@ def run_cell(cell: dict, cfg: dict, mix: dict, *, seed: int, seconds: float,
         f"{ {k: round(v * 1e3, 3) for k, v in rec.longest.items()} }; "
         f"longest garbage-collector pause {rec.gc_pause_s * 1e3:.3f} ms")
     log(f"[window] programs lowered inside the window: {counter.n}")
-    log_monitor(engine, log)
+    log(f"[window] engine counters: {rec.counters}")
+    log_monitor(engines, log)
+    if len(engines) > 1:
+        routed = [sum(r.replica == i for r in rec.requests)
+                  for i in range(len(engines))]
+        log(f"[replicas] devices: {[str(e.device) for e in engines]}")
+        log(f"[replicas] requests routed: {routed}, decode steps: "
+            f"{[c.get('steps', 0) for c in rec.replica_counters]}, "
+            f"tokens: {[c.get('tokens', 0) for c in rec.replica_counters]}")
+        log(f"[replicas] peak memory, bytes: {memory}")
 
     picked = sample(rec.requests, seed, cfg["reference"]["k"])
     buckets_by_id = dict(loop.buckets)
-    del loop, engine, run
+    del loop, engines, run
     gc.collect()
     t = time.perf_counter()
     gaps = compare(cfg, seed, picked, buckets_by_id, device, control)
-    log(f"[reference] {len(picked)} requests, {gaps['rows']} served tokens "
-        f"compared in {time.perf_counter() - t:.2f} s")
+    log(f"[reference] {len(picked)} requests (replicas "
+        f"{sorted({r.replica for r in picked})}), {gaps['rows']} served "
+        f"tokens compared in {time.perf_counter() - t:.2f} s")
     limit = cfg.get("gap_limit")
     served = judge(gaps["served"], unanswered, gaps["rows"], limit)
     correct, checks = served
@@ -266,7 +299,7 @@ def run_cell(cell: dict, cfg: dict, mix: dict, *, seed: int, seconds: float,
                                 limit)
     dev = {"platform": device.platform, "kind": kind,
            "count": jax.device_count(),
-           "memory_peak_bytes": memory}
+           "memory_peak_bytes": max(memory)}
     result = {"correct": correct, "attempted": len(attempted),
               "failed": unanswered, "metrics": metrics, "device": dev}
     if summary is not None:
@@ -278,6 +311,21 @@ def run_cell(cell: dict, cfg: dict, mix: dict, *, seed: int, seconds: float,
                             "widest_gap": gaps["served"]}
     result["checks"] = checks
     return result
+
+
+def devices_for(cell: dict, name: str, tool: str):
+    """The host's devices, or None after saying why they cannot serve the
+    cell: no TPU, or fewer devices than its chips."""
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        print(f"{tool}: needs a TPU; JAX found {devices[0].platform!r}",
+              file=sys.stderr)
+        return None
+    if len(devices) < cell["chips"]:
+        print(f"{tool}: {name} asks for {cell['chips']} chips, one replica "
+              f"on each; this host has {len(devices)}", file=sys.stderr)
+        return None
+    return devices
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -294,19 +342,13 @@ def main(argv: list[str] | None = None) -> int:
     cell = spec.workload(bench, args.workload)
     cfg = spec.config(cell["config"])
     mix = spec.traffic(cell["traffic"])
-    devices = jax.devices()
-    if devices[0].platform != "tpu":
-        print(f"run: needs a TPU; JAX found {devices[0].platform!r}",
-              file=sys.stderr)
-        return 2
-    if cell["chips"] != 1:
-        print(f"run: {args.workload} asks for {cell['chips']} chips; this "
-              f"harness serves one engine on one chip", file=sys.stderr)
+    devices = devices_for(cell, args.workload, "run")
+    if devices is None:
         return 2
     spec.peaks(devices[0].device_kind)
     use_compile_cache()
     result = run_cell(cell, cfg, mix, seed=args.seed, seconds=args.seconds,
-                      trace=bool(args.trace), device=devices[0],
+                      trace=bool(args.trace), devices=devices,
                       bench=bench, t_start=T_START,
                       keep_trace=args.keep_trace)
     for name, c in result["checks"].items():

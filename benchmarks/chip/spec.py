@@ -1,14 +1,19 @@
 """Finds what ``BENCHMARK.json`` names, by name, under this directory.
 
-  configs/<config>.json      a model configuration as it is run
+  configs/<config>.json      a model configuration as it is run; its
+                             ``arch`` names its architecture module
+  archs/<arch>.py            an architecture: the program's config, the
+                             weights' leaves, the float32 reference layer
+                             and the work counts (``archs/dense.py`` says
+                             what a module gives)
   traffic/<traffic>.json     a traffic mix for the one generator
   metrics/<metric>.py        the reader of a metric; ``a.b`` is read by
                              ``metrics/a.py`` (one reader serves every
                              cell group that a suffix names)
   peaks.json                 the chips' peaks, keyed by ``device_kind``
 
-A configuration, a mix or a metric is added with a file and an entry in
-``BENCHMARK.json``; nothing here changes.
+An architecture, a configuration, a mix or a metric is added with a file
+and an entry in ``BENCHMARK.json``; nothing here changes.
 """
 
 from __future__ import annotations
@@ -63,14 +68,28 @@ def metrics(bench: dict, cell: str, kind: str) -> list[dict]:
             if "workloads" not in m or cell in m["workloads"]]
 
 
+def _module(kind: str, stem: str, base: Path, what: str):
+    path = base / kind / f"{stem}.py"
+    if not path.is_file():
+        raise KeyError(f"no {kind}/{path.name} for {what}")
+    if path not in _LOADED:
+        spec = importlib.util.spec_from_file_location(
+            f"benchmarks.chip.{kind}.{stem}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _LOADED[path] = mod
+    return _LOADED[path]
+
+
+_LOADED: dict = {}
+
+
 def reader(metric: str, base: Path = HERE):
     """The ``read(run)`` function of a metric's reader file."""
-    stem = metric.split(".")[0]
-    path = base / "metrics" / f"{stem}.py"
-    if not path.is_file():
-        raise KeyError(f"no reader {path.name} for metric {metric!r}")
-    spec = importlib.util.spec_from_file_location(
-        f"benchmarks.chip.metrics.{stem}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module("metrics", metric.split(".")[0], base,
+                   f"metric {metric!r}").read
+
+
+def arch(name: str, base: Path = HERE):
+    """The architecture module ``archs/<name>.py``."""
+    return _module("archs", name, base, f"architecture {name!r}")

@@ -17,6 +17,13 @@ class Run:
     config: dict
     peak: dict
     trace: object = None             # xtrace.TraceSummary in a traced run
+    chips: int = 1                   # one replica on each
+
+    @property
+    def arch(self):
+        """The configuration's architecture module (its work counts)."""
+        from benchmarks.chip import spec
+        return spec.arch(self.config["arch"])
 
 
 def quantile(xs: list[float], q: float) -> float | None:

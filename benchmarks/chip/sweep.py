@@ -3,7 +3,8 @@
   for r in 2.0 2.4 2.8 3.2; do python3 benchmarks/chip/sweep.py \
       --workload <cell> --seed <n> --seconds 30 --rate $r; done
 
-Builds the cell's engine, offers its traffic mix (Poisson arrivals, the
+Builds the cell's engines as a run does (one replica on each of its chips,
+behind the mix's router), offers its traffic mix (Poisson arrivals, the
 mix's lengths) at ``--rate`` for ``--seconds``, and prints one line:
 requests due, first tokens inside the window, the queue left when it
 closed, the tails of time to first token and of the gaps between tokens,
@@ -32,10 +33,7 @@ for _p in (ROOT / "src", ROOT):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
 
-import jax  # noqa: E402
-
 from benchmarks.chip import run, spec, stats, traffic  # noqa: E402
-from benchmarks.chip.serve_loop import OpenLoop  # noqa: E402
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -49,14 +47,13 @@ def main(argv: list[str] | None = None) -> int:
     cell = spec.workload(bench, args.workload)
     cfg = spec.config(cell["config"])
     mix = dict(spec.traffic(cell["traffic"]), arrival="poisson")
-    device = jax.devices()[0]
-    if device.platform != "tpu" or cell["chips"] != 1:
-        print("sweep: needs a TPU and a one-chip cell", file=sys.stderr)
+    devices = run.devices_for(cell, args.workload, "sweep")
+    if devices is None:
         return 2
     run.use_compile_cache()
-    engine = run.build_engine(cfg, args.seed, device)
-    loop = OpenLoop(engine)
-    loop.warm_up(list(engine.sched.cfg.prefill_buckets))
+    engines = run.build_engines(cfg, args.seed, devices[:cell["chips"]])
+    loop = run.open_loop(engines, mix, args.seed)
+    loop.warm_up(list(engines[0].sched.cfg.prefill_buckets))
     print(f"[sweep] set-up {time.perf_counter() - T_START:.1f} s")
     mix["rate_per_s"] = args.rate
     reqs = traffic.generate(mix, args.seed, args.seconds, cfg["vocab_size"])
@@ -65,7 +62,10 @@ def main(argv: list[str] | None = None) -> int:
     ttft = [r.token_times[0] - r.due for r in got]
     print(json.dumps({
         "rate": args.rate, "due": len(reqs), "first_tokens": len(got),
-        "queue_at_close": len(engine.sched.queue),
+        "queue_at_close": sum(1 for r in reqs if r.submitted >= 0
+                              and r.prefill_start < 0),
+        "routed": [sum(r.replica == i for r in reqs)
+                   for i in range(len(engines))],
         "ttft_p50_ms": (stats.quantile(ttft, 0.5) or 0) * 1e3,
         "ttft_p90_ms": (stats.quantile(ttft, 0.9) or 0) * 1e3,
         "itl_p95_ms": (stats.quantile(stats.token_gaps(rec), 0.95)

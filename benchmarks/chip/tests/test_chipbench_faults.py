@@ -25,7 +25,7 @@ def _run(seed, control=False, trace=False):
     return run.run_cell(CELL, spec.load_json(DATA / "tiny.json"),
                         spec.load_json(DATA / "tiny-chat.json"), seed=seed,
                         seconds=SECONDS, trace=trace,
-                        device=jax.devices()[0], bench=BENCH,
+                        devices=jax.devices(), bench=BENCH,
                         t_start=time.perf_counter(), control=control,
                         log=lambda *a: None)
 

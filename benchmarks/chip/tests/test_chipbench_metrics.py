@@ -10,6 +10,8 @@ from benchmarks.chip import spec, stats, work
 from benchmarks.chip.serve_loop import Record, Step
 from benchmarks.chip.traffic import Request
 
+DENSE = spec.arch("dense")
+
 
 def _req(rid, due, times):
     return Request(rid=rid, due=due, prompt=[1] * 10, max_new_tokens=4,
@@ -83,9 +85,9 @@ def test_step_readers_and_silence_without_a_trace():
 def test_roofline_counts_live_work_and_stays_under_the_dense_program():
     cfg = spec.config("qwen3-0.6b")
     peak = spec.peaks("TPU v5 lite")
-    live = work.decode_step(cfg, [100, 2000])
+    live = DENSE.decode_step(cfg, Step(0.0, 0.0, [100, 2000]))
     # the program reads the whole 16 x 2048 cache whatever the lengths
-    dense = work.decode_step(cfg, [2048] * 16)
+    dense = DENSE.decode_step(cfg, Step(0.0, 0.0, [2048] * 16))
     assert live[1] < dense[1]
     assert work.least_time(*live, peak) == pytest.approx(
         live[1] / peak["hbm_bytes_per_s"])
@@ -94,12 +96,11 @@ def test_roofline_counts_live_work_and_stays_under_the_dense_program():
 @pytest.mark.parametrize("name", ["qwen3-0.6b", "h2o-danube-3-4b"])
 def test_parameter_arithmetic_matches_the_programs_count(name):
     """2 x N uses the matmul parameters: every layer and the head."""
-    from benchmarks.chip import run
     cfg = spec.config(name)
     embed = 0 if cfg["tie_word_embeddings"] else \
         cfg["vocab_size"] * cfg["hidden_size"]
-    assert work.matmul_params(cfg) + embed == \
-        run.program_config(cfg).param_count()
+    assert DENSE.matmul_params(cfg) + embed == \
+        DENSE.program_config(cfg).param_count()
 
 
 def test_mfu_counts_prompt_and_generated_tokens_in_the_window():
@@ -107,5 +108,5 @@ def test_mfu_counts_prompt_and_generated_tokens_in_the_window():
     reqs = [_req(0, 0.0, [0.5, 1.0, 3.0])]
     rec = Record(requests=reqs, seconds=2.0, stop=3.0)
     got = spec.reader("mfu.chat")(_run(rec))
-    want = work.request_flops(cfg, 10, 2) / (2.0 * 197e12) * 100
+    want = DENSE.request_flops(cfg, 10, 2) / (2.0 * 197e12) * 100
     assert got == pytest.approx(want)

@@ -3,6 +3,9 @@ slice of a trace recorded on a TPU v5e (testdata/)."""
 
 import _paths  # noqa: F401
 
+import time
+
+import jax
 import numpy as np
 import pytest
 from jax.profiler import ProfileData
@@ -95,3 +98,49 @@ def test_chip_slice_reduces_consistently():
     assert 0 < share < 100
     assert spec.reader("device_idle_share.chat")(run) == \
         pytest.approx(100 * idle / s.window_s)
+
+
+def test_a_replicas_idle_time_is_put_down_to_its_own_thread():
+    """Two replicas, one on each device: device n's idle time goes to the
+    spans of the thread that serves it (``bench.d<n>.*``), and to spans of
+    no replica, never to the other replica's."""
+    host = _plane(1, "/host:CPU", {"python3": [
+        ("bench.window", 100, 1000), ("bench.d0.decode", 100, 500),
+        ("bench.d1.telemetry", 300, 200), ("bench.d1.admit", 600, 400),
+        ("bench.submit", 900, 50)]})
+    dev0 = _plane(2, "/device:TPU:0", {
+        "XLA Modules": [("jit_decode_step(7)", 150, 300)],
+        "XLA Ops": [("fusion.1", 150, 300)]})
+    dev1 = _plane(3, "/device:TPU:1", {
+        "XLA Modules": [("jit_decode_step(7)", 200, 50)],
+        "XLA Ops": [("fusion.1", 200, 50)]})
+    s = xtrace.summarize(ProfileData.from_text_proto(
+        "\n".join((host, dev0, dev1))))
+    assert s.devices == 2 and s.busy_s == pytest.approx(175e-9)
+    assert s.program("decode_step") == (pytest.approx(350e-9), 2)
+    # device 0 idle: 100-150 (decode), 450-1100 (midpoint 775: none of its
+    # own; d1.admit is the other thread's).  Device 1 idle: 100-200
+    # (midpoint 150: none of its own), 250-1100 (midpoint 675: admit)
+    assert s.idle_by_span == {"decode": pytest.approx(50e-9),
+                              "none": pytest.approx(750e-9),
+                              "admit": pytest.approx(850e-9)}
+    assert s.phases.devices == 2
+
+
+def test_a_part_of_the_block_is_traced_with_its_own_window(tmp_path):
+    """``traced(part=(start, end))``: the profiler starts ``start`` seconds
+    into the block; the trace's window span covers [start, end], and a
+    span the block opened before the start is not in it."""
+    keep = tmp_path / "part.xplane.pb.gz"
+    with xtrace.traced(str(keep), part=(0.3, 0.6)) as holder:
+        with jax.profiler.TraceAnnotation("bench.early"):
+            time.sleep(0.1)
+        time.sleep(0.4)
+        with jax.profiler.TraceAnnotation("bench.late"):
+            time.sleep(0.1)
+        time.sleep(0.2)
+    names = {ev.name for plane in xtrace.load(str(keep)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert "bench.late" in names and "bench.early" not in names
+    assert 0.25 < holder.summary.window_s < 0.35

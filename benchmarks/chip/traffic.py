@@ -40,6 +40,7 @@ class Request:
     tokens: list[int] = field(default_factory=list)
     token_times: list[float] = field(default_factory=list)
     finished: bool = False
+    replica: int = -1             # the replica it was routed to
 
 
 def lognormal_quantiles(n: int, spec: dict) -> list[int]:

@@ -1,16 +1,18 @@
-"""Seeded weights of a dense decoder, made on the device from ``--seed``.
+"""Seeded weights of any architecture, made on the device from ``--seed``.
 
 The benchmark, not the program, makes the weights: one jitted call builds
-the whole tree in the layout the program's decoder takes, in bfloat16 on
-the serving device.  The reference rebuilds any single layer from the same
-seed (``layer_f32``), so it never takes an array the program has held.
+the whole tree in the layout the program's model takes, in bfloat16 on
+the serving device.  The architecture module (``archs/<arch>.py``) lists
+the leaves, their shapes and scales; this module draws them.  The
+reference rebuilds any single layer from the same seed (``layer_f32``),
+so it never takes an array the program has held.
 
 Every weight is an odd integer in [-255, 255] times a per-leaf scale.  An
 8-bit integer is exact in bfloat16 and the product is one IEEE multiply,
 so the values do not depend on how XLA fuses the generator: the stacked
 draw for the program and the per-layer draw for the reference agree bit
-for bit.  The scales follow the program's own initialiser: 1/sqrt(fan_in)
-for projections, 0.02 for the embedding, 1 for norm scales.
+for bit.  Each leaf's key is the seed's folded with a hash of the leaf's
+path (and, for a stacked leaf, with the layer's index).
 """
 
 from __future__ import annotations
@@ -35,36 +37,6 @@ def root_key(seed: int) -> jax.Array:
     return jax.random.fold_in(k, np.uint32(s >> 32))
 
 
-def dims(cfg: dict) -> dict:
-    d = cfg["hidden_size"]
-    hd = cfg.get("head_dim") or d // cfg["num_attention_heads"]
-    return {"d": d, "hd": hd, "hq": cfg["num_attention_heads"],
-            "hkv": cfg["num_key_value_heads"],
-            "ff": cfg["intermediate_size"], "vocab": cfg["vocab_size"],
-            "layers": cfg["num_hidden_layers"]}
-
-
-def layer_leaves(cfg: dict) -> dict:
-    """Per-layer leaves: path -> (shape, scale); scale None is a norm."""
-    m = dims(cfg)
-    d, hd, hq, hkv, ff = m["d"], m["hd"], m["hq"], m["hkv"], m["ff"]
-    leaves = {
-        ("ln1", "scale"): ((d,), None),
-        ("attn", "wq"): ((d, hq * hd), d),
-        ("attn", "wk"): ((d, hkv * hd), d),
-        ("attn", "wv"): ((d, hkv * hd), d),
-        ("attn", "wo"): ((hq * hd, d), hq * hd),
-        ("ln2", "scale"): ((d,), None),
-        ("mlp", "w_gate"): ((d, ff), d),
-        ("mlp", "w_up"): ((d, ff), d),
-        ("mlp", "w_down"): ((ff, d), ff),
-    }
-    if cfg.get("qk_norm"):
-        leaves[("attn", "q_norm", "scale")] = ((hd,), None)
-        leaves[("attn", "k_norm", "scale")] = ((hd,), None)
-    return leaves
-
-
 def _draw(key, shape, std: float | None, dtype):
     if std is None:
         return jnp.ones(shape, dtype)
@@ -85,39 +57,41 @@ def _set(tree: dict, path, value) -> None:
     tree[path[-1]] = value
 
 
-def _layer(key, cfg: dict, layer, dtype) -> dict:
+def _leaves(key, leaves: dict, dtype, index=None) -> dict:
+    """Draws {path: (shape, std[, dtype])} into a tree, each leaf in its
+    own dtype where it names one; a stacked leaf's key is its path's folded
+    with the layer's index."""
     out: dict = {}
-    for path, (shape, fan_in) in layer_leaves(cfg).items():
-        k = jax.random.fold_in(_leaf_key(key, ("layers",) + path), layer)
-        std = None if fan_in is None else 1.0 / math.sqrt(fan_in)
-        _set(out, path, _draw(k, shape, std, dtype))
+    for path, (shape, std, *own) in leaves.items():
+        k = _leaf_key(key, path)
+        if index is not None:
+            k = jax.random.fold_in(k, index)
+        _set(out, path, _draw(k, shape, std, own[0] if own else dtype))
     return out
 
 
-def _outer(key, cfg: dict, name: str, dtype):
-    m = dims(cfg)
-    k = _leaf_key(key, (name,))
-    if name == "embed":
-        return _draw(k, (m["vocab"], m["d"]), 0.02, dtype)
-    if name == "lm_head":
-        return _draw(k, (m["d"], m["vocab"]), 1.0 / math.sqrt(m["d"]), dtype)
-    return {"scale": _draw(k, (m["d"],), None, dtype)}      # ln_f
+def _stack(key, arch, cfg: dict, name: str, index, dtype) -> dict:
+    _, leaves = arch.stacks(cfg)[name]
+    return _leaves(key, {(name,) + p: v for p, v in leaves.items()}, dtype,
+                   index)[name]
 
 
-def outer_names(cfg: dict) -> tuple[str, ...]:
-    return ("embed", "ln_f") + (() if cfg.get("tie_word_embeddings")
-                                else ("lm_head",))
+def _arch(cfg: dict, arch):
+    if arch is not None:
+        return arch
+    from benchmarks.chip import spec
+    return spec.arch(cfg["arch"])
 
 
-def make_params(cfg: dict, seed: int, device) -> dict:
+def make_params(cfg: dict, seed: int, device, arch=None) -> dict:
     """The whole bfloat16 tree on ``device``, in one jitted call."""
-    n = dims(cfg)["layers"]
+    arch = _arch(cfg, arch)
 
     def build(key):
-        params = {name: _outer(key, cfg, name, jnp.bfloat16)
-                  for name in outer_names(cfg)}
-        params["layers"] = jax.vmap(
-            lambda i: _layer(key, cfg, i, jnp.bfloat16))(jnp.arange(n))
+        params = _leaves(key, arch.outer_leaves(cfg), jnp.bfloat16)
+        for name, (n, _) in arch.stacks(cfg).items():
+            params[name] = jax.vmap(lambda i, name=name: _stack(
+                key, arch, cfg, name, i, jnp.bfloat16))(jnp.arange(n))
         return params
 
     out = jax.sharding.SingleDeviceSharding(device)
@@ -139,19 +113,30 @@ def check_layout(params: dict, model) -> None:
 class LayerMaker:
     """Rebuilds weights one layer at a time, for the reference."""
 
-    def __init__(self, cfg: dict, seed: int) -> None:
+    def __init__(self, cfg: dict, seed: int, arch=None) -> None:
         self.cfg = cfg
+        self.arch = arch = _arch(cfg, arch)
         self.key = root_key(seed)
-        self._layer = jax.jit(lambda key, i: _f32(
-            _layer(key, cfg, i, jnp.bfloat16)))
-        self._outer = jax.jit(lambda key, name: _f32(
-            _outer(key, cfg, name, jnp.bfloat16)), static_argnums=1)
+        self._layer = jax.jit(lambda key, i, name: _f32(_stack(
+            key, arch, cfg, name, i, jnp.bfloat16)), static_argnums=2)
+        outer = arch.outer_leaves(cfg)
+        self._outer = jax.jit(lambda key, path: _f32(_under(_leaves(
+            key, {p: v for p, v in outer.items() if p[:len(path)] == path},
+            jnp.bfloat16), path)), static_argnums=1)
 
-    def layer_f32(self, i: int) -> dict:
-        return self._layer(self.key, jnp.int32(i))
+    def layer_f32(self, stack: str, i: int) -> dict:
+        """Layer ``i`` of the stack ``stack``, in float32."""
+        return self._layer(self.key, jnp.int32(i), stack)
 
-    def outer_f32(self, name: str):
-        return self._outer(self.key, name)
+    def outer_f32(self, path: tuple):
+        """The float32 leaf at ``path``, or the subtree under it."""
+        return self._outer(self.key, tuple(path))
+
+
+def _under(tree, path):
+    for p in path:
+        tree = tree[p]
+    return tree
 
 
 def _f32(tree):
